@@ -42,6 +42,16 @@ import numpy as np
 CLOSED, OPEN, HALF_OPEN = "closed", "open", "half-open"
 
 
+class TierFaultError(RuntimeError):
+    """A tier executor crashed (or was made to crash by injection).
+
+    This is the one exception the engine's failover loop reads as "tier
+    down": it trips the breaker and re-routes.  Any other exception from
+    an executor (a compile error, device out-of-memory, a bug) is not a
+    tier outage and propagates to the caller.
+    """
+
+
 @dataclasses.dataclass(frozen=True)
 class TierOutage:
     """Tier ``tier`` is dead (crashed / unreachable) on [start_s, end_s):

@@ -45,7 +45,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     q = q_ref[0]                                   # (rep, D)
     k = k_ref[0]                                   # (block_s, D)
     v = v_ref[0]
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
 
     s = jax.lax.dot_general(
         q.astype(jnp.float32), k.astype(jnp.float32),
@@ -86,23 +86,24 @@ def flash_decode(q, k_cache, v_cache, lengths, *, scale=None,
 
     grid = (b * hkv, s // block_s)
     kernel = functools.partial(_decode_kernel, scale=scale, block_s=block_s)
+    # lengths ride in SMEM by scalar prefetch; index maps take them last
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda g, si: (g,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, rep, d), lambda g, si: (g, 0, 0)),
-            pl.BlockSpec((1, block_s, d), lambda g, si: (g, si, 0)),
-            pl.BlockSpec((1, block_s, d), lambda g, si: (g, si, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, rep, d), lambda g, si: (g, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, rep, d), lambda g, si, lens: (g, 0, 0)),
+                pl.BlockSpec((1, block_s, d), lambda g, si, lens: (g, si, 0)),
+                pl.BlockSpec((1, block_s, d), lambda g, si, lens: (g, si, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, rep, d), lambda g, si, lens: (g, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((rep, 1), jnp.float32),
+                pltpu.VMEM((rep, 1), jnp.float32),
+                pltpu.VMEM((rep, d), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b * hkv, rep, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, d), jnp.float32),
-        ],
         interpret=interpret,
     )(lens, qr, kr, vr)
     return out.reshape(b, hkv, rep, d).reshape(b, h, d)

@@ -12,10 +12,9 @@ GQA: the q tile carries the `rep` query heads of one kv head
 queries — the same reuse argument that makes GQA decode bandwidth-
 efficient on TPU.
 
-Causal masking is positional (no mask tensor); fully-masked K blocks are
-skipped by the grid via block pruning in the index map (we keep them and
-mask instead: simpler, and XLA-CPU interpret mode is the validation
-target — noted as a TODO for real-TPU tuning).
+Causal masking is positional (no mask tensor).  Fully-masked K blocks
+are still visited and masked, not pruned from the grid: about 2x the
+needed work on long causal prompts, open for tuning on the chip.
 
 Padded batches: ``lengths`` (B,) optionally masks each sequence's valid
 KEY prefix (slot < length), the prefix-padding discipline of the serving
@@ -25,7 +24,8 @@ pre-trimming.  Rows whose query position is padding attend only to valid
 keys (garbage-in-padding stays confined to padding rows).  ``lengths``
 must be >= 1: a fully-masked row degenerates to exp(0)=1 weights on
 every key (the online-softmax max never leaves NEG_INF), same contract
-as the decode kernel and ``ref.attention_ref``; callers clamp.
+as the decode kernel and ``ref.attention_ref``; callers clamp.  The
+lengths reach the kernel in SMEM by scalar prefetch.
 """
 
 from __future__ import annotations
@@ -69,7 +69,7 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (bq * rep, block_k), 1)
-    s = jnp.where(k_pos < len_ref[0], s, NEG_INF)     # valid key prefix
+    s = jnp.where(k_pos < len_ref[pl.program_id(0)], s, NEG_INF)  # key prefix
     if causal:
         q_pos = (qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (bq, rep, block_k), 0)).reshape(bq * rep, block_k)
@@ -128,24 +128,28 @@ def flash_attention(q, k, v, *, causal: bool = True,
         _flash_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, seq_k=t)
 
+    # lengths ride in SMEM by scalar prefetch; index maps take them last
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda g, qi, ki: (g,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, rep * d), lambda g, qi, ki: (g, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda g, qi, ki: (g, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda g, qi, ki: (g, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, rep * d),
-                               lambda g, qi, ki: (g, qi, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, rep * d),
+                             lambda g, qi, ki, lens: (g, qi, 0)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda g, qi, ki, lens: (g, ki, 0)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda g, qi, ki, lens: (g, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, rep * d),
+                                   lambda g, qi, ki, lens: (g, qi, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((block_q * rep, 1), jnp.float32),   # running max
+                pltpu.VMEM((block_q * rep, 1), jnp.float32),   # running sum
+                pltpu.VMEM((block_q * rep, d), jnp.float32),   # o accumulator
+            ]),
         out_shape=jax.ShapeDtypeStruct((b * hkv, s, rep * d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q * rep, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_q * rep, 1), jnp.float32),   # running sum
-            pltpu.VMEM((block_q * rep, d), jnp.float32),   # o accumulator
-        ],
         interpret=interpret,
     )(lens, qr, kr, vr)
 

@@ -1,10 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the
-kernel *body* runs in Python per grid cell, which validates the tiling
-and carry logic; on TPU the same `pl.pallas_call` lowers to Mosaic.
-Wrappers handle padding to block multiples and auto-select interpret
-mode off the default backend.
+Off the TPU the kernels execute in interpret mode — the kernel *body*
+runs in Python per grid cell, which validates the tiling and carry
+logic; on TPU the same `pl.pallas_call` lowers to Mosaic (what Mosaic
+accepts is pinned by tests/test_tpu_compile.py).  Wrappers handle
+padding to block multiples and auto-select interpret mode off the
+default backend.
 """
 
 from __future__ import annotations
@@ -76,19 +77,42 @@ def flash_decode(q, k_cache, v_cache, lengths, *, block_s: int = 256,
                             interpret=interpret)
 
 
+def _fit_chunk(seq: int, chunk: int) -> int:
+    """Chunk no longer than the (8-aligned) sequence: TPU row tiles are
+    multiples of 8, so the scans pad the sequence to a chunk multiple."""
+    return min(chunk, -(-seq // 8) * 8)
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_wkv(r, k, v, log_w, u, s0=None, *, chunk: int = 32,
               interpret: bool | None = None):
-    """Chunked WKV6. Shapes as in repro.kernels.ref.rwkv6_ref."""
+    """Chunked WKV6. Shapes as in repro.kernels.ref.rwkv6_ref.
+
+    Any sequence length: the tail is padded with k=0, log_w=0 steps,
+    which leave the state untouched, and their outputs are dropped.
+    """
     interpret = _auto_interpret(interpret)
-    return _wkv.rwkv6_wkv(r, k, v, log_w, u, s0, chunk=chunk,
-                          interpret=interpret)
+    s = r.shape[1]
+    chunk = _fit_chunk(s, chunk)
+    r, k, v, log_w = (_pad_to(x, 1, chunk)[0] for x in (r, k, v, log_w))
+    y, s_t = _wkv.rwkv6_wkv(r, k, v, log_w, u, s0, chunk=chunk,
+                            interpret=interpret)
+    return y[:, :s], s_t
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, a_log, b_in, c_in, s0=None, *, chunk: int = 64,
              interpret: bool | None = None):
-    """Chunked Mamba2 SSD. Shapes as in repro.kernels.ref.ssd_ref."""
+    """Chunked Mamba2 SSD. Shapes as in repro.kernels.ref.ssd_ref.
+
+    Any sequence length: the tail is padded with dt=0 steps (no decay,
+    no input), which leave the state untouched, and their outputs are
+    dropped.
+    """
     interpret = _auto_interpret(interpret)
-    return _ssd.ssd_scan(x, dt, a_log, b_in, c_in, s0, chunk=chunk,
-                         interpret=interpret)
+    s = x.shape[1]
+    chunk = _fit_chunk(s, chunk)
+    x, dt, b_in, c_in = (_pad_to(t, 1, chunk)[0] for t in (x, dt, b_in, c_in))
+    y, s_t = _ssd.ssd_scan(x, dt, a_log, b_in, c_in, s0, chunk=chunk,
+                           interpret=interpret)
+    return y[:, :s], s_t

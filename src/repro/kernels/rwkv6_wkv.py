@@ -5,6 +5,7 @@ with the (P x P) state carried in VMEM scratch across chunk steps (same
 carry idiom as the flash kernels).  Per chunk (L x P tiles in VMEM):
 
     cum_t   = prefix-sum of log w within the chunk        (L,P)
+              (a lower-triangular matmul: Mosaic has no cumsum)
     A[t,j]  = (r_t e^{cum_{t-1}}) · (k_j e^{-cum_j}),  j<t    -> MXU matmul
     y       = A @ v + (u·(r k)) v   + (r e^{cum_{t-1}}) @ S
     S       = diag(e^{cum_L}) S + sum_j e^{cum_L - cum_j} k_j v_j^T
@@ -26,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_CHUNK = 32
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sT_ref,
@@ -42,16 +44,21 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sT_ref,
     lw = lw_ref[0].astype(jnp.float32)    # (L,P) <= 0
     u = u_ref[0].astype(jnp.float32)      # (1,P)
 
-    cum = jnp.cumsum(lw, axis=0)
+    l = r.shape[0]
+    ti = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+    tj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum); HIGHEST keeps the f32 log-decays exact on the MXU
+    tril = jnp.where(tj <= ti, 1.0, 0.0)
+    cum = jax.lax.dot_general(tril, lw, (((1,), (0,)), ((), ())),
+                              precision=_HIGHEST,
+                              preferred_element_type=jnp.float32)  # (L,P)
     cum_prev = cum - lw
     r_dec = r * jnp.exp(cum_prev)
     k_inc = k * jnp.exp(-cum)
 
-    l = r.shape[0]
     a = jax.lax.dot_general(r_dec, k_inc, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (L,L)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
-    tj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
     a = jnp.where(tj < ti, a, 0.0)
     bonus = jnp.sum(r * u * k, axis=-1, keepdims=True)           # (L,1)
 
@@ -62,10 +69,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sT_ref,
          + jax.lax.dot_general(r_dec, s_prev, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32))
 
-    wj = jnp.exp(cum[-1:, :] - cum)        # (L,P)
+    cum_last = cum[l - 1:l, :]             # (1,P) static slice
+    wj = jnp.exp(cum_last - cum)           # (L,P)
     inc = jax.lax.dot_general(k * wj, v, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (P,P)
-    s_scr[...] = s_prev * jnp.exp(cum[-1, :])[:, None] + inc
+    # diag(e^{cum_L}) S as a matmul: the decay indexes the state's rows,
+    # which a (1,P) lane vector cannot broadcast over without a transpose
+    pi = jax.lax.broadcasted_iota(jnp.int32, (s_prev.shape[0],) * 2, 0)
+    pj = jax.lax.broadcasted_iota(jnp.int32, (s_prev.shape[0],) * 2, 1)
+    decay = jnp.where(pi == pj, jnp.exp(cum_last), 0.0)          # (P,P)
+    s_scr[...] = jax.lax.dot_general(
+        decay, s_prev, (((1,), (0,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32) + inc
 
     y_ref[0] = y.astype(y_ref.dtype)
 

@@ -1,7 +1,8 @@
 """Pallas TPU kernel: Mamba2 SSD chunked scan.
 
 Grid (batch*head, chunk); carry = the (P x N) SSM state in VMEM scratch.
-Per chunk with L-row tiles (x (L,P), b/c (L,N), dt/log-decay (L,1)):
+Per chunk with L-row tiles (x (L,P), b/c (L,N), dt/log-decay (L,1), and
+dt/log-decay again as (1,L) rows, so no transpose is needed in-kernel):
 
     cum     = prefix-sum log decay                      (L,1) per-head scalar
     CB      = c @ b^T, masked lower-triangular, * e^{cum_t-cum_j} * dt_j
@@ -23,10 +24,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_CHUNK = 64
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, ld_ref, s0_ref, y_ref, sT_ref,
-                s_scr, *, chunk: int):
+def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, ld_ref, dtr_ref, ldr_ref, s0_ref,
+                y_ref, sT_ref, s_scr, *, chunk: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -38,15 +40,26 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, ld_ref, s0_ref, y_ref, sT_ref,
     cc = c_ref[0].astype(jnp.float32)      # (L,N)
     dt = dt_ref[0].astype(jnp.float32)     # (L,1)
     ld = ld_ref[0].astype(jnp.float32)     # (L,1) <= 0
+    dt_row = dtr_ref[0, 0].astype(jnp.float32)   # (1,L) same values
+    ld_row = ldr_ref[0, 0].astype(jnp.float32)   # (1,L)
 
     l = x.shape[0]
-    cum = jnp.cumsum(ld, axis=0)           # (L,1)
-    cb = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (L,L)
-    seg = cum - cum.reshape(1, l)          # seg[t,j] = cum_t - cum_j
+    n = bb.shape[1]
     ti = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
     tj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
-    scores = jnp.where(tj <= ti, cb * jnp.exp(seg), 0.0) * dt.reshape(1, l)
+    tril = jnp.where(tj <= ti, 1.0, 0.0)
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum), in both layouts: cum_t down the sublanes, cum_j along lanes
+    cum = jax.lax.dot_general(tril, ld, (((1,), (0,)), ((), ())),
+                              precision=_HIGHEST,
+                              preferred_element_type=jnp.float32)   # (L,1)
+    cum_row = jax.lax.dot_general(ld_row, tril, (((1,), (1,)), ((), ())),
+                                  precision=_HIGHEST,
+                                  preferred_element_type=jnp.float32)  # (1,L)
+    cb = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # (L,L)
+    seg = jnp.where(tj <= ti, cum - cum_row, -jnp.inf)  # cum_t - cum_j, j<=t
+    scores = cb * jnp.exp(seg) * dt_row
 
     s_prev = s_scr[...]                    # (N,P) state (key-major)
     y = (jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
@@ -55,10 +68,15 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, ld_ref, s0_ref, y_ref, sT_ref,
                                (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32))
 
-    wj = jnp.exp(cum[-1:] - cum) * dt      # (L,1)
+    cum_last = cum[l - 1:l, :]             # (1,1) static slice
+    wj = jnp.exp(cum_last - cum) * dt      # (L,1)
     inc = jax.lax.dot_general(bb * wj, x, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (N,P)
-    s_scr[...] = s_prev * jnp.exp(cum[-1, 0]) + inc
+    # the chunk's total decay as an (N,1) column, reduced from the lane
+    # layout: Mosaic cannot broadcast one scalar over both state axes
+    decay = jnp.exp(jnp.sum(jnp.broadcast_to(ld_row, (n, l)), axis=1,
+                            keepdims=True))
+    s_scr[...] = s_prev * decay + inc
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -105,6 +123,8 @@ def ssd_scan(x, dt, a_log, b_in, c_in, s0=None, *, chunk: int = DEFAULT_CHUNK,
             pl.BlockSpec((1, chunk, n), lambda g, ci: (g, ci, 0)),
             pl.BlockSpec((1, chunk, 1), lambda g, ci: (g, ci, 0)),
             pl.BlockSpec((1, chunk, 1), lambda g, ci: (g, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda g, ci: (g, ci, 0, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda g, ci: (g, ci, 0, 0)),
             pl.BlockSpec((1, n, p), lambda g, ci: (g, 0, 0)),
         ],
         out_specs=[
@@ -117,7 +137,8 @@ def ssd_scan(x, dt, a_log, b_in, c_in, s0=None, *, chunk: int = DEFAULT_CHUNK,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xx, bb, cc, dd, ll, ss)
+    )(xx, bb, cc, dd, ll, dd.reshape(bsz * h, nc, 1, chunk),
+      ll.reshape(bsz * h, nc, 1, chunk), ss)
 
     y = y.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)
     s_t = s_t.reshape(bsz, h, n, p).transpose(0, 1, 3, 2)   # back to (P,N)

@@ -30,15 +30,23 @@ MULTI_POD_SHAPE = (2, 16, 16)
 MULTI_POD_AXES = ("pod", "data", "model")
 
 
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axes: the sharding policy hands GSPMD
+    NamedShardings and lets it propagate, which Explicit axes (the
+    installed default) refuse at gathers and matmuls."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = MULTI_POD_AXES if multi_pod else SINGLE_POD_AXES
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
-    """Small mesh for tests (requires >=prod(shape) visible devices)."""
-    return jax.make_mesh(shape, axes)
+    """Small mesh over the visible devices (needs >= prod(shape))."""
+    return _auto_mesh(shape, axes)
 
 
 def chips(mesh) -> int:

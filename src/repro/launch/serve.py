@@ -6,8 +6,13 @@
 Pass ``--mesh DxM`` (e.g. ``--mesh 2x2``) to serve the LM sharded over a
 device mesh (``data`` x ``model`` axes); on CPU set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` first so the host
-platform exposes N devices.  Decode output is bit-for-bit identical to
-the unsharded run.
+platform exposes N devices.  Sharded parameters are initialised straight
+into their shardings, so no device ever holds the whole model.
+``--mixer-impl pallas`` routes rwkv6/mamba2 prefill through the Pallas
+kernels.  The persistent compile cache is on (``launch/compile_cache``).
+
+``main`` returns the engine's ``stats()`` with ``--tiered`` and the
+generated token block otherwise.
 """
 
 from __future__ import annotations
@@ -21,11 +26,12 @@ import numpy as np
 from repro.core.latency_model import DeviceProfile, LinearLatencyModel
 from repro.core.length_regressor import LinearN2M
 from repro.core.profiles import make_profile
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import resolve
 from repro.runtime.engine import CollaborativeEngine, Tier
 from repro.runtime.serving import GenerationSession, build_executor
-from repro.runtime.sharded import make_sharded_session
+from repro.runtime.sharded import init_sharded, make_sharded_session
 
 
 def main(argv=None):
@@ -39,19 +45,25 @@ def main(argv=None):
     ap.add_argument("--mesh", default=None, metavar="DxM",
                     help="shard the LM over a (data, model) host mesh, "
                          "e.g. 2x2 (needs that many visible devices)")
+    ap.add_argument("--mixer-impl", default="xla", choices=("xla", "pallas"),
+                    help="rwkv6/mamba2 prefill backend")
     args = ap.parse_args(argv)
 
-    r = resolve(args.arch, size="smoke" if args.smoke else "full")
+    enable_compile_cache()
+    r = resolve(args.arch, size="smoke" if args.smoke else "full",
+                mixer_impl=args.mixer_impl)
     model, cfg = r.model, r.cfg
-    params = model.init(jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(0)
     if args.mesh:
         d, m = (int(x) for x in args.mesh.lower().split("x"))
         mesh = make_host_mesh((d, m))
+        batch = min(args.requests, 8)
+        params, _ = init_sharded(model, key, mesh, batch_size=batch)
         sess = make_sharded_session(model, params, mesh, max_len=64,
-                                    batch_size=min(args.requests, 8))
+                                    batch_size=batch)
         print(f"[serve] sharded over {d}x{m} mesh, layout={sess.layout}")
     else:
-        sess = GenerationSession(model, params, max_len=64)
+        sess = GenerationSession(model, model.init(key), max_len=64)
     rng = np.random.default_rng(0)
 
     if not args.tiered:
@@ -64,7 +76,7 @@ def main(argv=None):
         t0 = time.perf_counter()
         sess.generate(prompts, max_new=args.max_new)
         print(f"[serve] warm: {time.perf_counter()-t0:.3f}s")
-        return
+        return out
 
     profile = make_profile("cp2", seed=0)
     edge_exec = build_executor(sess, kind="solo", max_new=args.max_new,
@@ -92,6 +104,7 @@ def main(argv=None):
     s = engine.stats()
     print(f"[serve] {s['requests']} reqs, mean {s['mean_latency_s']*1e3:.1f}ms,"
           f" offload {s['offload_frac']*100:.0f}%")
+    return s
 
 
 if __name__ == "__main__":

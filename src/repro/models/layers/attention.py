@@ -224,7 +224,6 @@ def attn_decode_seq_sharded(p, cfg: ModelConfig, x, cache_k, cache_v, pos,
     flash-decode's split-K reduction, expressed with lax collectives.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b = x.shape[0]
     s_max = cache_k.shape[1]
@@ -271,7 +270,7 @@ def attn_decode_seq_sharded(p, cfg: ModelConfig, x, cache_k, cache_v, pos,
         o = o / jnp.maximum(l_g, 1e-30)[..., None].astype(o_loc.dtype)
         return o.reshape(bl, 1, h, dh), ck, cv
 
-    y, ck, cv = shard_map(
+    y, ck, cv = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None, None), P(bspec, None, None, None),
                   P(bspec, None, None, None),
@@ -280,7 +279,7 @@ def attn_decode_seq_sharded(p, cfg: ModelConfig, x, cache_k, cache_v, pos,
         out_specs=(P(bspec, None, None, None),
                    P(bspec, seq_axis, None, None),
                    P(bspec, seq_axis, None, None)),
-        check_rep=False,
+        check_vma=False,
     )(q, k_new, v_new, cache_k, cache_v, pos)
     y = linear(p["o"], y.reshape(x.shape[0], 1, -1))
     return y, ck, cv

@@ -100,7 +100,8 @@ def mamba2_full(p, cfg: ModelConfig, x, *,
     ``impl="pallas"`` dispatches the inner SSD scan to the
     :func:`repro.kernels.ops.ssd_scan` Pallas kernel (interpret mode on
     CPU, Mosaic on TPU); ``"xla"`` keeps the pure-jnp chunked scan.  Both
-    compute the identical chunk algorithm — parity is pinned in
+    compute the same chunk algorithm (the kernel with 8-aligned chunks of
+    ``cfg.ssm.chunk``) and agree to float32 rounding, pinned in
     tests/test_bigmodel_serving.py.
     """
     s = cfg.ssm
@@ -120,14 +121,11 @@ def mamba2_full(p, cfg: ModelConfig, x, *,
     a = -jnp.exp(p["a_log"])                            # (nh,) negative
     log_decay = dt * a                                  # (B,S,nh)  <= 0
 
-    L = pick_chunk(seq, s.chunk)
-    nc = seq // L
-
     if impl == "pallas":
         from repro.kernels.ops import ssd_scan
         y, h_final = ssd_scan(
             xh.astype(jnp.float32), dt, p["a_log"],
-            bh.astype(jnp.float32), ch.astype(jnp.float32), chunk=L)
+            bh.astype(jnp.float32), ch.astype(jnp.float32), chunk=s.chunk)
         y = y.astype(xh.dtype)
         h_final = h_final.astype(xh.dtype)
         y = y + xh * p["d_skip"][None, None, :, None].astype(xh.dtype)
@@ -139,6 +137,9 @@ def mamba2_full(p, cfg: ModelConfig, x, *,
         return y, MambaState(ssm=h_final, conv=zxbcdt_tail)
 
     from repro.sharding.ctx import constrain_batch
+
+    L = pick_chunk(seq, s.chunk)
+    nc = seq // L
 
     def chunked(xh, bh, ch, dt, log_decay):
         # chunk-major (NC,B,L,...) for a scan over chunks: per-chunk

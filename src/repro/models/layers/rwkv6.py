@@ -119,8 +119,8 @@ def rwkv6_full(p, cfg: ModelConfig, x, state: RWKVState, *,
     ``impl="pallas"`` dispatches the inner WKV recurrence to the
     :func:`repro.kernels.ops.rwkv6_wkv` Pallas kernel (interpret mode on
     CPU, Mosaic on TPU); ``"xla"`` keeps the pure-jnp chunked scan.  Both
-    compute the identical chunk algorithm — parity is pinned in
-    tests/test_bigmodel_serving.py.
+    compute the same chunk algorithm and agree to float32 rounding,
+    pinned in tests/test_bigmodel_serving.py.
     """
     rc = cfg.rwkv
     b, seq, d = x.shape
@@ -132,16 +132,12 @@ def rwkv6_full(p, cfg: ModelConfig, x, state: RWKVState, *,
     vh = v.reshape(b, seq, hnum, pdim)
     lw = log_w.reshape(b, seq, hnum, pdim)               # f32
 
-    from repro.models.layers.mamba2 import pick_chunk
-    L = pick_chunk(seq, 32)
-    nc = seq // L
-
     if impl == "pallas":
         from repro.kernels.ops import rwkv6_wkv
         y, s_final = rwkv6_wkv(
             rh.astype(jnp.float32), kh.astype(jnp.float32),
             vh.astype(jnp.float32), lw, p["u"],
-            state.wkv.astype(jnp.float32), chunk=L)
+            state.wkv.astype(jnp.float32))
         y = _group_norm(p, y, cfg.norm_eps, hnum)
         y = (y * jax.nn.silu(g.astype(jnp.float32))).astype(x.dtype)
         y = linear(p["o"], y)
@@ -150,7 +146,11 @@ def rwkv6_full(p, cfg: ModelConfig, x, state: RWKVState, *,
                               shift_cm=state.shift_cm)
         return y, new_state
 
+    from repro.models.layers.mamba2 import pick_chunk
     from repro.sharding.ctx import constrain_batch
+
+    L = pick_chunk(seq, 32)
+    nc = seq // L
 
     # (NC,B,L,H,P) chunk-major for the scan
     def to_chunks(x):

@@ -368,14 +368,18 @@ def build_translate_batched(model, params, make_state, *,
                             compiled: bool = True):
     """Shared scaffolding behind the models' ``make_translate_batched``.
 
-    ``make_state(src (B,N), src_mask (B,N)) -> batched decode state`` is
-    the only model-specific piece (encode + state assembly); stepping is
-    ``model.decode_step`` with a leading batch dim.  ``compiled=True``
-    jits encoder + state init + the whole scan decode into ONE dispatch
-    per (B, N) shape; ``compiled=False`` is the per-sequence host loop
-    (the paper-faithful, linear-in-M timing path).  Both return
-    ``translate(src, src_mask=None, forced_len=None) ->
-    (lengths (B,), tokens (B, steps))``.
+    ``make_state(params, src (B,N), src_mask (B,N)) -> batched decode
+    state`` is the only model-specific piece (encode + state assembly);
+    stepping is ``model.decode_step`` with a leading batch dim.
+    ``compiled=True`` jits encoder + state init + the whole scan decode
+    into ONE dispatch per (B, N) shape, with the parameters as an
+    argument (closed over, they would be baked into every executable as
+    constants: slow to compile and a copy per shape); ``compiled=False``
+    is the per-sequence host loop (the paper-faithful, linear-in-M
+    timing path).  Both return ``translate(src, src_mask=None,
+    forced_len=None) -> (lengths (B,), tokens (B, steps))``; the compiled
+    one exposes its jitted ``(params, src, src_mask)`` step as
+    ``translate.jitted``.
     """
     if not compiled:
         translate = model.make_translate(params)
@@ -385,11 +389,10 @@ def build_translate_batched(model, params, make_state, *,
                                           forced_len)
         return translate_host
 
-    step = lambda st, tok: model.decode_step(params, st, tok)
-
     @functools.partial(jax.jit, static_argnames=("forced_len",))
-    def run(src, src_mask, forced_len=None):
-        state = make_state(src, src_mask)
+    def run(params, src, src_mask, forced_len=None):
+        state = make_state(params, src, src_mask)
+        step = lambda st, tok: model.decode_step(params, st, tok)
         return batched_greedy_decode(step, state, src.shape[0],
                                      model.cfg.max_decode_len, forced_len)
 
@@ -397,8 +400,9 @@ def build_translate_batched(model, params, make_state, *,
         src = jnp.asarray(src, jnp.int32)
         if src_mask is None:
             src_mask = jnp.ones(src.shape, jnp.float32)
-        return run(src, jnp.asarray(src_mask), forced_len=forced_len)
+        return run(params, src, jnp.asarray(src_mask), forced_len=forced_len)
 
+    translate_batch.jitted = run
     return translate_batch
 
 

@@ -88,7 +88,7 @@ class GRUSeq2Seq:
         """
         return build_translate_batched(
             self, params,
-            lambda src, mask: self.encode(params, src, mask),
+            lambda p, src, mask: self.encode(p, src, mask),
             compiled=compiled)
 
     def make_encode_states(self, params):

@@ -148,8 +148,8 @@ class BiLSTMSeq2Seq:
         ``compiled=False`` the per-sequence host loop (paper-faithful
         timing path).
         """
-        def make_state(src, mask):
-            enc_outs, carries, m = self.encode(params, src, mask)
+        def make_state(p, src, mask):
+            enc_outs, carries, m = self.encode(p, src, mask)
             return (carries, enc_outs, m)
 
         return build_translate_batched(self, params, make_state,

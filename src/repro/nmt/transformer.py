@@ -379,9 +379,9 @@ class MarianTransformer:
         shape; ``compiled=False`` is the per-sequence host loop whose
         wall-clock stays linear in M (the Fig. 2a timing path).
         """
-        def make_state(src, mask):
-            enc_outs, m = self.encode(params, src, mask)
-            return self.init_cache(params, enc_outs, m)
+        def make_state(p, src, mask):
+            enc_outs, m = self.encode(p, src, mask)
+            return self.init_cache(p, enc_outs, m)
 
         return build_translate_batched(self, params, make_state,
                                        compiled=compiled)

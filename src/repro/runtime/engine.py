@@ -89,6 +89,7 @@ from repro.core.faults import (
     CircuitBreaker,
     FaultSchedule,
     RetryPolicy,
+    TierFaultError,
     make_breakers,
 )
 from repro.data.pipeline import TokenBatcher
@@ -568,12 +569,12 @@ class CollaborativeEngine:
 
         Per attempt: mask = this request's already-failed tiers ∪ tiers
         whose breaker refuses dispatch; re-run the placement decision
-        excluding the mask; on an injected (or real executor) failure,
-        trip the breaker, advance the virtual clock by the detection
-        time + exponential backoff with jitter, and go again.  The
-        request is shed when every tier is masked (with a
-        ``retry_after_s`` hint), when the retry budget runs out, or when
-        its deadline expires mid-retry."""
+        excluding the mask; on an injected failure (or a TierFaultError
+        from a real executor), trip the breaker, advance the virtual
+        clock by the detection time + exponential backoff with jitter,
+        and go again.  The request is shed when every tier is masked
+        (with a ``retry_after_s`` hint), when the retry budget runs out,
+        or when its deadline expires mid-retry."""
         n = int(len(tokens))
         now0 = now
         t = now
@@ -632,8 +633,8 @@ class CollaborativeEngine:
             if fail is None:
                 try:
                     m_out, exec_s = tier.run(tokens, d.m_hat, self.rng)
-                except Exception:
-                    fail = "down"   # a real executor raising = crashed
+                except TierFaultError:
+                    fail = "down"   # the executor reported a crashed tier
             if fail is not None:
                 self._record_failure(k, t)
                 failed.append(k)

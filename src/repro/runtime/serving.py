@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.faults import TierFaultError
 from repro.data.tokenizer import EOS_ID, PAD_ID
 from repro.models.model import LM
 from repro.nmt.common import greedy_update, scan_greedy_steps
@@ -140,16 +141,6 @@ def _solo_executor(session: "GenerationSession", *, max_new: int = 16,
     return executor
 
 
-class TierFaultError(RuntimeError):
-    """A tier executor crashed (or was made to crash by injection).
-
-    The :class:`~repro.runtime.engine.CollaborativeEngine` failover loop
-    treats ANY exception escaping ``Tier.run`` as a tier-down signal —
-    this named type exists so fault-injection wrappers and tests can
-    raise/catch something more specific than ``RuntimeError``.
-    """
-
-
 def _faulty_wrap(executor: Callable, should_fail,
                  *, message: str = "injected tier fault") -> Callable:
     """Wrap a REAL tier executor with deterministic fault injection.
@@ -184,6 +175,23 @@ def _faulty_wrap(executor: Callable, should_fail,
     return faulty
 
 
+def _block_and_lengths(batch, lengths, vocab_clip):
+    """A drained (b, width) block and its true per-row prompt lengths
+    (derived from trailing PADs when ``lengths`` is None)."""
+    toks = np.asarray(batch, np.int32)
+    if toks.ndim != 2:
+        raise ValueError("batched executor expects a (b, width) block")
+    if vocab_clip is not None:
+        toks = np.minimum(toks, vocab_clip - 1)
+    if lengths is not None:
+        return toks, np.asarray(lengths, np.int32)
+    real = toks != PAD_ID
+    # width minus trailing pads; clamp to >= 1 for all-pad rows
+    trailing = np.where(real.any(1), np.argmax(real[:, ::-1], axis=1),
+                        toks.shape[1])
+    return toks, np.maximum(toks.shape[1] - trailing, 1).astype(np.int32)
+
+
 def _batched_executor(session: "GenerationSession", *,
                       max_new: int = 16,
                       vocab_clip: Optional[int] = None) -> Callable:
@@ -199,19 +207,7 @@ def _batched_executor(session: "GenerationSession", *,
     """
 
     def executor(batch: np.ndarray, lengths: Optional[Sequence[int]] = None):
-        toks = np.asarray(batch, np.int32)
-        if toks.ndim != 2:
-            raise ValueError("batched executor expects a (b, width) block")
-        if vocab_clip is not None:
-            toks = np.minimum(toks, vocab_clip - 1)
-        if lengths is None:
-            real = toks != PAD_ID
-            # width minus trailing pads; clamp to >= 1 for all-pad rows
-            trailing = np.where(real.any(1), np.argmax(real[:, ::-1], axis=1),
-                                toks.shape[1])
-            lens_in = np.maximum(toks.shape[1] - trailing, 1).astype(np.int32)
-        else:
-            lens_in = np.asarray(lengths, np.int32)
+        toks, lens_in = _block_and_lengths(batch, lengths, vocab_clip)
         if session.supports_ragged or np.all(lens_in == toks.shape[1]):
             m_out, out = session.generate_with_lengths(
                 toks, max_new=max_new, lengths=lens_in)
@@ -228,6 +224,36 @@ def _batched_executor(session: "GenerationSession", *,
                 results[r] = (int(m_out[j]), out[j, :max(int(m_out[j]), 1)])
         return results
 
+    return executor
+
+
+def _nmt_batched_executor(model, params, *,
+                          vocab_clip: Optional[int] = None) -> Callable:
+    """REAL batched executor for an NMT seq2seq model (the paper's tiers).
+
+    Same contract as :func:`_batched_executor`, served by the model's
+    compiled ``make_translate_batched``.  Blocks are padded to
+    power-of-two (batch, width) buckets, so a tier compiles one translate
+    per bucket; padding rows and columns are masked out and dropped.  The
+    translate function is exposed as ``executor.translate``.
+    """
+    translate = model.make_translate_batched(params)
+
+    def executor(batch: np.ndarray, lengths: Optional[Sequence[int]] = None):
+        toks, lens_in = _block_and_lengths(batch, lengths, vocab_clip)
+        b, w = toks.shape
+        bb, wb = _next_pow2(b), _next_pow2(w, floor=8)
+        src = np.full((bb, wb), PAD_ID, np.int32)
+        src[:b, :w] = toks
+        lens = np.zeros((bb,), np.int32)
+        lens[:b] = lens_in
+        mask = (np.arange(wb)[None, :] < lens[:, None]).astype(np.float32)
+        m_out, out = translate(src, mask)
+        m_out, out = np.asarray(m_out, np.int32), np.asarray(out, np.int32)
+        return [(int(m_out[i]), out[i, :max(int(m_out[i]), 1)])
+                for i in range(b)]
+
+    executor.translate = translate
     return executor
 
 
@@ -281,7 +307,9 @@ def build_executor(session_or_model, *, kind: str = "solo",
       the per-request ``executor(tokens) -> (m_out, out_tokens)``.
     * ``"batched"`` — same input; returns the REAL batched
       ``executor(batch, lengths=None) -> [(m_out, tokens), ...]`` the
-      engine's ``submit_batch`` drives (``Tier.batched_executor``).
+      engine's ``submit_batch`` drives (``Tier.batched_executor``).  With
+      ``params=``, ``session_or_model`` is an NMT *model* instead, served
+      by its compiled batched translate.
     * ``"split"`` — ``session_or_model`` is an NMT *model* and
       ``params=`` its parameters; returns the ``(encode_executor,
       decode_executor)`` pair for a partitioned placement
@@ -298,6 +326,9 @@ def build_executor(session_or_model, *, kind: str = "solo",
     if kind == "solo":
         executor = _solo_executor(session_or_model, max_new=max_new,
                                   vocab_clip=vocab_clip)
+    elif kind == "batched" and params is not None:
+        executor = _nmt_batched_executor(session_or_model, params,
+                                         vocab_clip=vocab_clip)
     elif kind == "batched":
         executor = _batched_executor(session_or_model, max_new=max_new,
                                      vocab_clip=vocab_clip)

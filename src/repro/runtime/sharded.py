@@ -24,7 +24,7 @@ tests/test_bigmodel_serving.py.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 
@@ -60,19 +60,41 @@ def infer_layout(cfg, mesh) -> str:
     return "tp" if (has_heads and heads_ok) else "ddp"
 
 
+def _policy(model: LM, mesh, batch_size: int, layout: str,
+            fsdp: bool) -> ShardingPolicy:
+    if layout == "auto":
+        layout = infer_layout(model.cfg, mesh)
+    return make_policy(mesh, batch_size=batch_size, layout=layout, fsdp=fsdp)
+
+
+def init_sharded(model: LM, key, mesh, *, batch_size: int = 8,
+                 layout: str = "auto", fsdp: bool = True
+                 ) -> Tuple[object, ShardingPolicy]:
+    """Initialise ``model``'s parameters directly into their shardings.
+
+    ``model.init`` is jitted with the policy's shardings as
+    ``out_shardings``, so each device draws only its own shard: the whole
+    model never sits on one device (a published-width LM may not fit
+    there).  Same values as ``model.init(key)``.  Returns ``(params,
+    policy)`` like :func:`shard_lm`.
+    """
+    pol = _policy(model, mesh, batch_size, layout, fsdp)
+    shardings = to_shardings(mesh, param_specs(pol, model.params_spec()))
+    return jax.jit(model.init, out_shardings=shardings)(key), pol
+
+
 def shard_lm(model: LM, params, mesh, *, batch_size: int = 8,
              layout: str = "auto", fsdp: bool = True
              ) -> Tuple[object, ShardingPolicy]:
-    """Place ``params`` on ``mesh`` under the sharding policy.
+    """Place existing ``params`` on ``mesh`` under the sharding policy.
 
     Returns ``(sharded_params, policy)``; ``layout="auto"`` delegates to
     :func:`infer_layout`.  The returned params carry committed
     NamedShardings, so any jit consuming them (the session entry points)
     compiles a partitioned executable without explicit in_shardings.
+    Params that already carry these shardings are not moved.
     """
-    if layout == "auto":
-        layout = infer_layout(model.cfg, mesh)
-    pol = make_policy(mesh, batch_size=batch_size, layout=layout, fsdp=fsdp)
+    pol = _policy(model, mesh, batch_size, layout, fsdp)
     shardings = to_shardings(
         mesh, param_specs(pol, jax.eval_shape(lambda: params)))
     return jax.device_put(params, shardings), pol

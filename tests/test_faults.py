@@ -232,6 +232,30 @@ def test_engine_real_executor_crash_fails_over():
     assert crashing.calls["faults"] == 1         # call 1 never happened at 0
 
 
+def test_engine_real_executor_other_error_propagates():
+    """Only TierFaultError means "tier down": any other error from a real
+    executor (a compile failure, device OOM, a bug) surfaces to the
+    caller and is never served by failover, even with retries armed."""
+    calls = []
+
+    def broken(tokens):
+        calls.append(1)
+        raise RuntimeError("device out of memory")
+
+    edge = Tier(DeviceProfile("e", LinearLatencyModel(2e-3, 8e-3, 0.01),
+                              0.0), executor=broken)
+    cloud = Tier(DeviceProfile("c", LinearLatencyModel(4e-4, 1.6e-3, 2e-3),
+                               0.0), rtt_fn=lambda t: 5.0)
+    eng = CollaborativeEngine(tiers=[edge, cloud],   # WAN: edge always wins
+                              n2m=LinearN2M(1.0, 0.0),
+                              seed=0, retry=RetryPolicy())
+    with pytest.raises(RuntimeError, match="out of memory"):
+        eng.submit(np.zeros(4, np.int32), now_s=0.0)
+    assert calls == [1]                          # no retry, no second tier
+    assert eng.retry_count == 0 and eng.failover_count == 0
+    assert eng.fault_failures.sum() == 0 and not eng.results
+
+
 # --------------------------------------------------------- DES parity --
 def _des_setup(seed=5):
     npu = DeviceProfile("npu", LinearLatencyModel(4e-4, 1.6e-3, 4e-3), 0.05)

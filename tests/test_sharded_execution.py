@@ -31,6 +31,7 @@ def test_sharded_train_step_executes():
     """One real AdamW step of a smoke arch on a 2x4 mesh."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_host_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import smoke_config
         from repro.models.model import LM
@@ -38,7 +39,7 @@ def test_sharded_train_step_executes():
                                            batch_specs, to_shardings)
         from repro.training.train_loop import init_train_state, make_train_step
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         cfg = smoke_config("qwen3-8b")
         model = LM(cfg)
         state = init_train_state(model, jax.random.PRNGKey(0))
@@ -70,13 +71,14 @@ def test_sharded_decode_matches_single_device():
     """Sharded serve_step == single-device decode_step numerically."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_host_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import smoke_config
         from repro.models.model import LM
         from repro.sharding.policy import (make_policy, param_specs,
                                            decode_state_specs, to_shardings)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         cfg = smoke_config("qwen3-8b")
         model = LM(cfg)
         params = model.init(jax.random.PRNGKey(0))
@@ -111,11 +113,12 @@ def test_shard_map_flash_decode_matches_reference():
     """The §Perf decode optimization is numerically exact on a real mesh."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_host_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.models.config import LayerGroup, ModelConfig
         from repro.models.layers import attention as att
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         cfg = ModelConfig(
             name="t", arch_type="dense", d_model=64, vocab_size=128,
             num_heads=8, num_kv_heads=4, head_dim=16, d_ff=128,
@@ -153,11 +156,12 @@ def test_shard_map_flash_decode_matches_reference():
 def test_moe_sharded_forward_matches_single_device():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_host_mesh
         from repro.configs import smoke_config
         from repro.models.model import LM
         from repro.sharding.policy import make_policy, param_specs, to_shardings
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         cfg = smoke_config("qwen3-moe-30b-a3b")
         model = LM(cfg)
         params = model.init(jax.random.PRNGKey(0))

@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core.latency_model import (
     ActivationCostModel,
     DeviceProfile,
@@ -323,7 +324,8 @@ class MultiTierScheduler(BaseScheduler):
         """Predicted output length in tokens for ``n`` input tokens
         (N→M regressor, floored at 1 so plane predictions stay
         positive) — the estimator every T_exe term is priced at."""
-        return max(float(np.asarray(self.n2m.predict(float(n)))), 1.0)
+        with tracing.span("sched.m_hat"):
+            return max(float(np.asarray(self.n2m.predict(float(n)))), 1.0)
 
     def queue_delay(self, k: int, backlog_s: float, in_system: int,
                     servers: int) -> float:
@@ -375,17 +377,28 @@ class MultiTierScheduler(BaseScheduler):
         removes unhealthy tiers from the candidate set (their predicted
         totals become ``inf``); the caller guarantees at least one tier
         stays eligible."""
+        with tracing.span("sched.decide"):
+            m_hat, totals = self._whole_totals(n, now_s, queue_delay_s)
+            totals = self._mask_totals(totals, exclude)
+            pick = self._explore_override(self._select(totals), exclude)
+            return MultiTierDecision(pick, tuple(totals), m_hat)
+
+    def _whole_totals(self, n: int, now_s: float,
+                      queue_delay_s: Optional[Sequence[float]]
+                      ) -> Tuple[float, List[float]]:
+        """(M_hat, per-tier whole-request totals) on the jnp prediction
+        path shared by `decide` and `decide_plan` — the float op order the
+        N=2 reduction to :meth:`CNMTScheduler.decide` is pinned on."""
         m_hat = self.m_hat(n)
         payload = float(bytes_for_tokens(n + m_hat, self.bytes_per_token))
         totals: List[float] = []
         for k, tier in enumerate(self.tiers):
-            t_exe = float(np.asarray(tier.model.predict(float(n), m_hat)))
+            with tracing.span("sched.t_exe", tier=k):
+                t_exe = float(np.asarray(tier.model.predict(float(n), m_hat)))
             t_tx = 0.0 if tier.tx is None else tier.tx.tx_time(now_s, payload)
             q = 0.0 if queue_delay_s is None else float(queue_delay_s[k])
             totals.append(t_exe + t_tx + q)
-        totals = self._mask_totals(totals, exclude)
-        pick = self._explore_override(self._select(totals), exclude)
-        return MultiTierDecision(pick, tuple(totals), m_hat)
+        return m_hat, totals
 
     def decide_fast(self, n: float, m_hat: float, now_s: float,
                     queue_delay_s: Optional[Sequence[float]] = None,
@@ -503,17 +516,11 @@ class MultiTierScheduler(BaseScheduler):
         ``plan`` attached).  ``tier`` is always the plan's decode tier —
         per-tier admission/reroute logic downstream is unchanged.
         """
-        m_hat = self.m_hat(n)
-        payload = float(bytes_for_tokens(n + m_hat, self.bytes_per_token))
-        totals: List[float] = []
-        for k, tier in enumerate(self.tiers):
-            t_exe = float(np.asarray(tier.model.predict(float(n), m_hat)))
-            t_tx = 0.0 if tier.tx is None else tier.tx.tx_time(now_s, payload)
-            q = 0.0 if queue_delay_s is None else float(queue_delay_s[k])
-            totals.append(t_exe + t_tx + q)
-        totals = self._mask_totals(totals, exclude)
-        return self._plan_decision(float(n), m_hat, now_s, queue_delay_s,
-                                   totals, exclude)
+        with tracing.span("sched.decide"):
+            m_hat, totals = self._whole_totals(n, now_s, queue_delay_s)
+            totals = self._mask_totals(totals, exclude)
+            return self._plan_decision(float(n), m_hat, now_s,
+                                       queue_delay_s, totals, exclude)
 
     def decide_plan_fast(self, n: float, m_hat: float, now_s: float,
                          queue_delay_s: Optional[Sequence[float]] = None,

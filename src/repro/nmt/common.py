@@ -322,9 +322,10 @@ def build_encode_states(model, params, encode_data):
     ``encode_data(src (B,N), src_mask (B,N)) -> pytree`` is the
     model-specific encoder pass; the wrapper jits it and packs the
     result into :class:`EncoderStates` with the per-row source lengths.
+    The jitted program is named ``nmt_encode_states``.
     """
     @jax.jit
-    def run(src, src_mask):
+    def nmt_encode_states(src, src_mask):
         data = encode_data(src, src_mask)
         lens = jnp.sum((src_mask > 0).astype(jnp.int32), axis=-1)
         return EncoderStates(data, lens)
@@ -333,7 +334,7 @@ def build_encode_states(model, params, encode_data):
         src = jnp.asarray(src, jnp.int32)
         if src_mask is None:
             src_mask = jnp.ones(src.shape, jnp.float32)
-        return run(src, jnp.asarray(src_mask))
+        return nmt_encode_states(src, jnp.asarray(src_mask))
 
     return encode_states
 
@@ -347,19 +348,20 @@ def build_decode_from_states(model, params, state_from_data):
     cross-attention K/V cache decoder-side so only the raw memory
     crosses the wire).  The decode itself is the exact
     :func:`batched_greedy_decode` scan the fused path runs — parity with
-    ``make_translate_batched`` is pinned bit-for-bit in tests.
+    ``make_translate_batched`` is pinned bit-for-bit in tests.  The
+    jitted program is named ``nmt_decode_states``.
     """
     step = lambda st, tok: model.decode_step(params, st, tok)
 
     @functools.partial(jax.jit, static_argnames=("forced_len",))
-    def run(states, forced_len=None):
+    def nmt_decode_states(states, forced_len=None):
         state = state_from_data(states.data)
         batch = states.src_lens.shape[0]
         return batched_greedy_decode(step, state, batch,
                                      model.cfg.max_decode_len, forced_len)
 
     def decode_from_states(states, forced_len=None):
-        return run(states, forced_len=forced_len)
+        return nmt_decode_states(states, forced_len=forced_len)
 
     return decode_from_states
 
@@ -379,7 +381,8 @@ def build_translate_batched(model, params, make_state, *,
     timing path).  Both return ``translate(src, src_mask=None,
     forced_len=None) -> (lengths (B,), tokens (B, steps))``; the compiled
     one exposes its jitted ``(params, src, src_mask)`` step as
-    ``translate.jitted``.
+    ``translate.jitted``, named ``nmt_translate`` (``jit_nmt_translate``
+    on a profiler trace's XLA Modules line).
     """
     if not compiled:
         translate = model.make_translate(params)
@@ -390,7 +393,7 @@ def build_translate_batched(model, params, make_state, *,
         return translate_host
 
     @functools.partial(jax.jit, static_argnames=("forced_len",))
-    def run(params, src, src_mask, forced_len=None):
+    def nmt_translate(params, src, src_mask, forced_len=None):
         state = make_state(params, src, src_mask)
         step = lambda st, tok: model.decode_step(params, st, tok)
         return batched_greedy_decode(step, state, src.shape[0],
@@ -400,9 +403,10 @@ def build_translate_batched(model, params, make_state, *,
         src = jnp.asarray(src, jnp.int32)
         if src_mask is None:
             src_mask = jnp.ones(src.shape, jnp.float32)
-        return run(params, src, jnp.asarray(src_mask), forced_len=forced_len)
+        return nmt_translate(params, src, jnp.asarray(src_mask),
+                             forced_len=forced_len)
 
-    translate_batch.jitted = run
+    translate_batch.jitted = nmt_translate
     return translate_batch
 
 

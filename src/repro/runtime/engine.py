@@ -83,6 +83,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from repro import tracing
 from repro.core.calibration import OnlineCalibrator
 from repro.core.faults import (
     OPEN,
@@ -414,6 +415,9 @@ class CollaborativeEngine:
         self.shed_count = np.zeros(len(self.tiers), np.int64)
         self._t0 = time.perf_counter()
         self._next_id = 0
+        # trace ids of submit_batch requests, in submission order (the
+        # ``trace`` attr of their ``repro.tracing`` spans)
+        self._next_trace = 0
 
         # -- fault tolerance (ISSUE 8) ----------------------------------
         # ``faults`` is injection ground truth the dispatcher never routes
@@ -939,15 +943,32 @@ class CollaborativeEngine:
         Deadline feasibility still uses slot-start predictions — the
         queueing a member induces on its batch peers shows up in their
         measured latency, not in their admission test.
+
+        Traced as an ``engine.submit_batch`` span; request i's spans
+        carry trace id ``base + i`` (``base`` counts this engine's
+        earlier requests).
         """
         now = self._now() if now_s is None else now_s
+        base = self._next_trace
+        self._next_trace += len(requests)
+        ids = list(range(base, base + len(requests)))
+        with tracing.span("engine.submit_batch",
+                          trace=ids[0] if len(ids) == 1 else tuple(ids)):
+            return self._submit_batch(requests, now, deadline_s, tag, ids)
+
+    def _submit_batch(self, requests: Sequence[np.ndarray], now: float,
+                      deadline_s: Optional[float], tag: Optional[str],
+                      ids: List[int]) -> List[RequestResult]:
         if self._ft:
             # fault-tolerant batch serving degenerates to per-request
             # failover dispatch: a member's failure/retry timeline is
             # per-request state a shared batched generate cannot carry
-            return [self._notify(self._submit_ft(np.asarray(t, np.int32),
-                                                 now, deadline_s), tag)
-                    for t in requests]
+            out = []
+            for trace, t in zip(ids, requests):
+                with tracing.bind(trace):
+                    out.append(self._notify(self._submit_ft(
+                        np.asarray(t, np.int32), now, deadline_s), tag))
+            return out
         results: List[Optional[RequestResult]] = [None] * len(requests)
         groups: Dict[int, List[tuple]] = {}
         pending = [0] * len(self.tiers)
@@ -956,8 +977,9 @@ class CollaborativeEngine:
             tokens = np.asarray(tokens, np.int32)
             n = int(len(tokens))
             qd = [occ.queue_delay(now) for occ in self._occ]
-            d = (self.scheduler.decide_plan(n, now, qd) if split_ready
-                 else self.scheduler.decide(n, now, qd))
+            with tracing.bind(ids[i]):
+                d = (self.scheduler.decide_plan(n, now, qd) if split_ready
+                     else self.scheduler.decide(n, now, qd))
             k = self._admit(d, now, deadline_s, pending)
             if k < 0:
                 results[i] = self._shed(n, d, deadline_s)
@@ -969,7 +991,9 @@ class CollaborativeEngine:
                 # split members run per-request: their decode leg enters
                 # tier k's virtual queue at its own states-arrival time,
                 # which a shared batch block could not represent
-                results[i] = self._submit_split(tokens, d, now, deadline_s)
+                with tracing.bind(ids[i]):
+                    results[i] = self._submit_split(tokens, d, now,
+                                                    deadline_s)
                 continue
             groups.setdefault(k, []).append((i, tokens, d))
 
@@ -987,14 +1011,17 @@ class CollaborativeEngine:
             for j, (_, toks, _) in enumerate(members):
                 tb.add(j, toks)
             while (nb := tb.next_batch()) is not None:
-                ids, block = nb
-                lens = [len(members[j][1]) for j in ids]
-                t0 = time.perf_counter()
-                outs = tier.batched_executor(block, lens)
-                exec_s = time.perf_counter() - t0
+                rows, block = nb
+                lens = [len(members[j][1]) for j in rows]
+                traces = [ids[members[j][0]] for j in rows]
+                with tracing.bind(traces[0] if len(traces) == 1
+                                  else tuple(traces)):
+                    t0 = time.perf_counter()
+                    outs = tier.batched_executor(block, lens)
+                    exec_s = time.perf_counter() - t0
                 wait, service_s = self._occ[k].assign_batch(
-                    now, exec_s, len(ids))
-                for j, (m_out, _) in zip(ids, outs):
+                    now, exec_s, len(rows))
+                for j, (m_out, _) in zip(rows, outs):
                     i, toks, d = members[j]
                     results[i] = self._complete(
                         k, d, len(toks), int(m_out), exec_s, wait,

@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.faults import TierFaultError
 from repro.data.tokenizer import EOS_ID, PAD_ID
 from repro.models.model import LM
@@ -236,22 +237,32 @@ def _nmt_batched_executor(model, params, *,
     power-of-two (batch, width) buckets, so a tier compiles one translate
     per bucket; padding rows and columns are masked out and dropped.  The
     translate function is exposed as ``executor.translate``.
+
+    Traced as an ``exec.translate`` span (attrs ``b``, ``w``: the padded
+    bucket) holding ``exec.dispatch`` (the asynchronous enqueue) and
+    ``exec.wait`` (the device work and the read-back).
     """
     translate = model.make_translate_batched(params)
 
     def executor(batch: np.ndarray, lengths: Optional[Sequence[int]] = None):
-        toks, lens_in = _block_and_lengths(batch, lengths, vocab_clip)
-        b, w = toks.shape
-        bb, wb = _next_pow2(b), _next_pow2(w, floor=8)
-        src = np.full((bb, wb), PAD_ID, np.int32)
-        src[:b, :w] = toks
-        lens = np.zeros((bb,), np.int32)
-        lens[:b] = lens_in
-        mask = (np.arange(wb)[None, :] < lens[:, None]).astype(np.float32)
-        m_out, out = translate(src, mask)
-        m_out, out = np.asarray(m_out, np.int32), np.asarray(out, np.int32)
-        return [(int(m_out[i]), out[i, :max(int(m_out[i]), 1)])
-                for i in range(b)]
+        with tracing.span("exec.translate") as sp:
+            toks, lens_in = _block_and_lengths(batch, lengths, vocab_clip)
+            b, w = toks.shape
+            bb, wb = _next_pow2(b), _next_pow2(w, floor=8)
+            sp.set(b=bb, w=wb)
+            src = np.full((bb, wb), PAD_ID, np.int32)
+            src[:b, :w] = toks
+            lens = np.zeros((bb,), np.int32)
+            lens[:b] = lens_in
+            mask = (np.arange(wb)[None, :] < lens[:, None]).astype(
+                np.float32)
+            with tracing.span("exec.dispatch"):
+                m_out, out = translate(src, mask)
+            with tracing.span("exec.wait"):
+                m_out = np.asarray(m_out, np.int32)
+                out = np.asarray(out, np.int32)
+            return [(int(m_out[i]), out[i, :max(int(m_out[i]), 1)])
+                    for i in range(b)]
 
     executor.translate = translate
     return executor

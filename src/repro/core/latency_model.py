@@ -25,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,9 +51,18 @@ class LinearLatencyModel:
         return self
 
     def predict(self, n, m):
-        n = jnp.asarray(n, jnp.float32)
-        m = jnp.asarray(m, jnp.float32)
-        return self.alpha_n * n + self.alpha_m * m + self.beta
+        """The plane at (N, M) in float32.  A ``jax.Array`` input (a tracer
+        under ``jit`` included) gives a ``jax.Array``; host inputs are
+        evaluated in NumPy in the same dtype and op order, bit-identical
+        and with no device dispatch."""
+        if isinstance(n, jax.Array) or isinstance(m, jax.Array):
+            n = jnp.asarray(n, jnp.float32)
+            m = jnp.asarray(m, jnp.float32)
+            return self.alpha_n * n + self.alpha_m * m + self.beta
+        n = np.asarray(n, np.float32)
+        m = np.asarray(m, np.float32)
+        return (float(self.alpha_n) * n + float(self.alpha_m) * m
+                + float(self.beta))
 
     def predict_legs(self, n, m):
         """Split the plane into (encode, decode) leg predictions.

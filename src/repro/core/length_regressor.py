@@ -22,8 +22,12 @@ advanced output length estimation methods"):
   captures mild nonlinearity at extreme lengths; an optional quantile knob
   lets the scheduler hedge latency-critical decisions.
 
-All estimators share fit(N, M) / predict(N) with jnp arrays and are
-deterministic given their inputs.
+All estimators share fit(N, M) / predict(N) and are deterministic given
+their inputs.  ``predict`` follows its input: a ``jax.Array`` (a tracer
+under ``jit`` included) gives a ``jax.Array``; a Python scalar, a list or
+a NumPy array is evaluated in NumPy on the host, in the float dtype and
+the op order ``jax.numpy`` would use, so the result is a NumPy value
+bit-identical to the ``jnp`` one and nothing is dispatched to a device.
 """
 
 from __future__ import annotations
@@ -31,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -88,8 +93,12 @@ class LinearN2M:
         return self
 
     def predict(self, n):
-        n = jnp.asarray(n)
-        return self.gamma * n + self.delta
+        if isinstance(n, jax.Array):
+            return self.gamma * jnp.asarray(n) + self.delta
+        # Python floats are weak in NumPy 2 as in JAX: the arithmetic stays
+        # in the float dtype jnp would pick (float32 unless x64 is on)
+        n = np.asarray(n, jax.dtypes.canonicalize_dtype(np.float64))
+        return float(self.gamma) * n + float(self.delta)
 
     # --- quality metrics reported in the paper's Fig. 3 caption -----------
     def r2(self, n, m) -> float:
@@ -119,8 +128,9 @@ class MeanN2M:
         return self
 
     def predict(self, n):
-        n = jnp.asarray(n)
-        return jnp.full(n.shape, self.mean_m, dtype=jnp.float32)
+        if isinstance(n, jax.Array):
+            return jnp.full(n.shape, self.mean_m, dtype=jnp.float32)
+        return np.full(np.shape(n), self.mean_m, np.float32)
 
 
 @dataclasses.dataclass
@@ -221,5 +231,6 @@ class BucketN2M:
         if below.any() or above.any():
             lin = np.asarray(self._fallback.predict(n_arr))
             out = np.where(below | above, lin, out)
-        res = jnp.asarray(out, jnp.float32)
+        res = (jnp.asarray if isinstance(n, jax.Array) else np.asarray)(
+            out, np.float32)
         return res if np.ndim(n) else res[0]

@@ -45,6 +45,7 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro import tracing
@@ -242,8 +243,9 @@ class MultiTierScheduler(BaseScheduler):
     predicted total is within the margin of the minimum, prefer the
     fastest *local* tier (no network variance).  With tiers
     ``[edge(local), cloud(remote)]`` and zero queue delays this picks the
-    same device as :meth:`CNMTScheduler.decide` bit-for-bit (same jnp
-    prediction path, same float op order).
+    same device as :meth:`CNMTScheduler.decide` bit-for-bit (same host
+    float32 prediction path, bit-identical to ``jnp``; same float op
+    order).
     """
 
     def __init__(self, tiers: Sequence[SchedTier], n2m: LinearN2M, *,
@@ -323,9 +325,12 @@ class MultiTierScheduler(BaseScheduler):
     def m_hat(self, n: float) -> float:
         """Predicted output length in tokens for ``n`` input tokens
         (N→M regressor, floored at 1 so plane predictions stay
-        positive) — the estimator every T_exe term is priced at."""
-        with tracing.span("sched.m_hat"):
-            return max(float(np.asarray(self.n2m.predict(float(n)))), 1.0)
+        positive) — the estimator every T_exe term is priced at.  The
+        span's ``host`` attr says whether it was evaluated on the host."""
+        with tracing.span("sched.m_hat") as sp:
+            m = self.n2m.predict(float(n))
+            sp.set(host=not isinstance(m, jax.Array))
+            return max(float(np.asarray(m)), 1.0)
 
     def queue_delay(self, k: int, backlog_s: float, in_system: int,
                     servers: int) -> float:
@@ -386,15 +391,18 @@ class MultiTierScheduler(BaseScheduler):
     def _whole_totals(self, n: int, now_s: float,
                       queue_delay_s: Optional[Sequence[float]]
                       ) -> Tuple[float, List[float]]:
-        """(M_hat, per-tier whole-request totals) on the jnp prediction
-        path shared by `decide` and `decide_plan` — the float op order the
-        N=2 reduction to :meth:`CNMTScheduler.decide` is pinned on."""
+        """(M_hat, per-tier whole-request totals) on the host float32
+        prediction path (bit-identical to ``jnp``) shared by `decide` and
+        `decide_plan` — the float op order the N=2 reduction to
+        :meth:`CNMTScheduler.decide` is pinned on."""
         m_hat = self.m_hat(n)
         payload = float(bytes_for_tokens(n + m_hat, self.bytes_per_token))
         totals: List[float] = []
         for k, tier in enumerate(self.tiers):
-            with tracing.span("sched.t_exe", tier=k):
-                t_exe = float(np.asarray(tier.model.predict(float(n), m_hat)))
+            with tracing.span("sched.t_exe", tier=k) as sp:
+                t_exe = tier.model.predict(float(n), m_hat)
+                sp.set(host=not isinstance(t_exe, jax.Array))
+                t_exe = float(np.asarray(t_exe))
             t_tx = 0.0 if tier.tx is None else tier.tx.tx_time(now_s, payload)
             q = 0.0 if queue_delay_s is None else float(queue_delay_s[k])
             totals.append(t_exe + t_tx + q)
@@ -509,7 +517,7 @@ class MultiTierScheduler(BaseScheduler):
                     queue_delay_s: Optional[Sequence[float]] = None,
                     *, exclude: Optional[frozenset] = None
                     ) -> MultiTierDecision:
-        """Plan-aware single-request rule (jnp prediction path).
+        """Plan-aware single-request rule (host prediction path).
 
         Whole-request totals use the exact `decide` arithmetic, so with
         splits disabled this is `decide` bit-for-bit (plus the chosen

@@ -1,9 +1,13 @@
-"""Unit + property tests for the N->M length estimators (paper Fig. 3)."""
+"""Unit + property tests for the N->M length estimators (paper Fig. 3),
+and the host evaluation of their predictions and of the latency plane."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.core.latency_model import LinearLatencyModel
 from repro.core.length_regressor import (
     BucketN2M,
     HuberN2M,
@@ -119,3 +123,55 @@ def test_property_linear_fit_equivariance(gamma, delta, scale):
     scaled = LinearN2M().fit(n, scale * m)
     assert scaled.gamma == pytest.approx(scale * base.gamma, rel=1e-3, abs=1e-4)
     assert scaled.delta == pytest.approx(scale * base.delta, rel=1e-3, abs=1e-3)
+
+
+
+# ------------------------------------- host path == jnp path, bit for bit ---
+INPUTS = {
+    "py_int": lambda r: int(r.integers(1, 300)),
+    "py_float": lambda r: float(r.uniform(0.0, 300.0)),
+    "np0d_int": lambda r: np.asarray(r.integers(1, 300)),
+    "np0d_float": lambda r: np.asarray(r.uniform(0.0, 300.0)),
+    "np1d_int": lambda r: r.integers(1, 300, size=7),
+    "np1d_float": lambda r: r.uniform(0.0, 300.0, size=7),
+}
+KINDS = ["linear", "ridge", "mean", "bucket", "plane"]
+
+
+def _predictor(kind, r):
+    """``kind``'s ``predict`` with random coefficients, and its arity."""
+    if kind == "plane":
+        coef = r.uniform(-1e-2, 1e-1, size=3).tolist()
+        return LinearLatencyModel(*coef).predict, 2
+    if kind == "mean":
+        return MeanN2M(float(r.uniform(1.0, 200.0))).predict, 1
+    if kind == "bucket":
+        n = r.uniform(20.0, 250.0, 400)       # inputs below/above fall back
+        m = r.uniform(0.3, 2.0) * n + r.normal(0.0, 3.0, n.size)
+        return BucketN2M(n_buckets=8).fit(n, m).predict, 1
+    cls = {"linear": LinearN2M, "ridge": RidgeN2M}[kind]
+    return cls(gamma=float(r.uniform(-2.0, 3.0)),
+               delta=float(r.uniform(-20.0, 20.0))).predict, 1
+
+
+@pytest.mark.parametrize("kind,inp,x64", [
+    *[(k, i, False) for k in KINDS for i in INPUTS],
+    ("linear", "py_float", True),
+    ("plane", "np1d_float", True),
+])
+def test_host_predict_is_bit_identical_to_jnp(kind, inp, x64):
+    """A host input is evaluated in NumPy and returns a NumPy value with
+    the bits the ``jnp`` path (``jnp.asarray`` input) returns; the ``jnp``
+    input still returns a ``jax.Array``."""
+    r = np.random.default_rng([KINDS.index(kind), list(INPUTS).index(inp)])
+    with jax.enable_x64(x64):
+        for _ in range(40):
+            predict, arity = _predictor(kind, r)
+            args = [INPUTS[inp](r) for _ in range(arity)]
+            host = predict(*args)
+            dev = predict(*map(jnp.asarray, args))
+            assert isinstance(host, (np.ndarray, np.generic))
+            assert isinstance(dev, jax.Array)
+            dev = np.asarray(dev)
+            assert host.dtype == dev.dtype and host.shape == dev.shape
+            assert np.asarray(host).tobytes() == dev.tobytes()

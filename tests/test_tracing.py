@@ -5,7 +5,8 @@ Covers: span nesting and parent ids, the trace id a request's spans
 share, the bounded deque and its dropped count, ``enable(False)``,
 compile records attributed to the span that compiled, the spans one
 ``CollaborativeEngine.submit_batch`` records over a modelled and a real
-tier, and that the recorder changes no result.
+tier, that a decision runs on the host, and that the recorder changes no
+result.
 """
 
 import dataclasses
@@ -207,6 +208,7 @@ def test_submit_batch_records_the_decision_and_translate_spans(
         assert sorted(r.attrs["tier"] for r in kids
                       if r.name == "sched.t_exe") == [0, 1]
         assert all(r.attrs["trace"] == i for r in kids)
+        assert all(r.attrs["host"] is True for r in kids)
     # one translate per request served at the edge, with its two children
     edge = [i for i, r in enumerate(res) if r.tier_name == "edge"]
     trans = by["exec.translate"]
@@ -217,6 +219,28 @@ def test_submit_batch_records_the_decision_and_translate_spans(
         assert [r.name for r in kids] == ["exec.dispatch", "exec.wait"]
         assert all(r.attrs["trace"] == t.attrs["trace"] for r in kids)
         assert t.t0_ns <= kids[0].t0_ns <= kids[1].t1_ns <= t.t1_ns
+
+
+def test_decision_and_modelled_tier_create_no_device_array():
+    """The regressor and the planes run on the host: a decision, and a
+    request served by a modelled tier, neither put an array on a device
+    nor read one back."""
+    edge = Tier(DeviceProfile("edge", LinearLatencyModel(1e-3, 1e-3, 0.0),
+                              0.05), name="edge")
+    cloud = Tier(DeviceProfile("cloud", LinearLatencyModel(1e-4, 1e-4, 0.0),
+                               0.05), name="cloud", rtt_fn=lambda t: 0.01)
+    eng = CollaborativeEngine(tiers=[edge, cloud], n2m=LinearN2M(0.8, 1.0),
+                              seed=3)
+    toks = np.arange(3, 33, dtype=np.int32)
+    with jax.transfer_guard("disallow"):
+        d = eng.scheduler.decide(18, 0.0)
+        res = eng.submit_batch([toks[:4], toks], now_s=1.0)
+    assert d.m_hat == np.float32(0.8 * np.float32(18.0) + 1.0)
+    assert [r.tier_name for r in res] == ["edge", "cloud"]
+    kids = [r for r in tracing.spans()
+            if r.name in ("sched.m_hat", "sched.t_exe")]
+    assert len(kids) == 3 * 3
+    assert all(r.attrs["host"] is True for r in kids)
 
 
 def test_decide_fast_carries_no_span(marian_executor):

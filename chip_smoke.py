@@ -179,8 +179,9 @@ def paper_phase(seed: int) -> None:
     mask = (jnp.arange(32)[None, :]
             < jnp.asarray([32, 20, 9, 4])[:, None]).astype(jnp.float32)
     tgt = jnp.asarray(rng.integers(4, MARIAN_VOCAB, (4, 16)), jnp.int32)
+    block = jnp.concatenate([src, mask.sum(1, dtype=jnp.int32)[:, None]], 1)
     _compile_with_kernel("served translate", execute.translate.jitted,
-                         params, src, mask)
+                         params, block)
 
     def two_steps(m):
         def f(p, src, mask):

@@ -366,6 +366,27 @@ def build_decode_from_states(model, params, state_from_data):
     return decode_from_states
 
 
+def row_lengths(src_mask, batch: int, width: int) -> np.ndarray:
+    """(B,) int32 row lengths, on the host, from a batched translate's
+    ``src_mask``: None (every row full), the lengths themselves, or a
+    (B, N) prefix mask.
+
+    Raises ``ValueError`` for a mask that is not a prefix mask (a real
+    position after a padded one): the compiled translate rebuilds the
+    mask from these lengths, and the host loop trims each row to them.
+    """
+    if src_mask is None:
+        return np.full((batch,), width, np.int32)
+    if np.ndim(src_mask) == 1:
+        return np.asarray(src_mask, np.int32)
+    mask = np.asarray(src_mask) > 0
+    lens = mask.sum(axis=-1).astype(np.int32)
+    if not np.array_equal(mask, np.arange(width) < lens[:, None]):
+        raise ValueError("src_mask must be a prefix mask: each row's real "
+                         "positions first, then its padding")
+    return lens
+
+
 def build_translate_batched(model, params, make_state, *,
                             compiled: bool = True):
     """Shared scaffolding behind the models' ``make_translate_batched``.
@@ -379,10 +400,18 @@ def build_translate_batched(model, params, make_state, *,
     constants: slow to compile and a copy per shape); ``compiled=False``
     is the per-sequence host loop (the paper-faithful, linear-in-M
     timing path).  Both return ``translate(src, src_mask=None,
-    forced_len=None) -> (lengths (B,), tokens (B, steps))``; the compiled
-    one exposes its jitted ``(params, src, src_mask)`` step as
-    ``translate.jitted``, named ``nmt_translate`` (``jit_nmt_translate``
-    on a profiler trace's XLA Modules line).
+    forced_len=None) -> (lengths (B,), tokens (B, steps))``, where
+    ``src_mask`` is a (B, N) prefix mask or the (B,) int32 row lengths it
+    is the prefix of (None: every row full).
+
+    The compiled one folds the lengths into the block as one more int32
+    column on the host and hands that one array to its jitted
+    ``(params, block)`` step, exposed as ``translate.jitted`` and named
+    ``nmt_translate`` (``jit_nmt_translate`` on a profiler trace's XLA
+    Modules line): the call uploads it, and the program splits it again
+    and builds the float32 prefix mask from the lengths.  One upload of a
+    (B, N + 1) array costs less than two on one TPU v5e (PERF.md).
+    ``translate.h2d`` is the number of host arrays that call uploads.
     """
     if not compiled:
         translate = model.make_translate(params)
@@ -393,20 +422,24 @@ def build_translate_batched(model, params, make_state, *,
         return translate_host
 
     @functools.partial(jax.jit, static_argnames=("forced_len",))
-    def nmt_translate(params, src, src_mask, forced_len=None):
+    def nmt_translate(params, block, forced_len=None):
+        src, src_lens = block[:, :-1], block[:, -1]
+        src_mask = (jnp.arange(src.shape[1])[None, :]
+                    < src_lens[:, None]).astype(jnp.float32)
         state = make_state(params, src, src_mask)
         step = lambda st, tok: model.decode_step(params, st, tok)
         return batched_greedy_decode(step, state, src.shape[0],
                                      model.cfg.max_decode_len, forced_len)
 
     def translate_batch(src, src_mask=None, forced_len=None):
-        src = jnp.asarray(src, jnp.int32)
-        if src_mask is None:
-            src_mask = jnp.ones(src.shape, jnp.float32)
-        return nmt_translate(params, src, jnp.asarray(src_mask),
-                             forced_len=forced_len)
+        src = np.asarray(src, np.int32)
+        block = np.empty((src.shape[0], src.shape[1] + 1), np.int32)
+        block[:, :-1] = src
+        block[:, -1] = row_lengths(src_mask, *src.shape)
+        return nmt_translate(params, block, forced_len=forced_len)
 
     translate_batch.jitted = nmt_translate
+    translate_batch.h2d = 1
     return translate_batch
 
 
@@ -417,15 +450,13 @@ def host_translate_batched(translate, src_tokens, src_mask=None,
     Runs ``translate`` (a model's ``make_translate`` closure) row by row
     over a prefix-padded batch — one jitted dispatch per token per
     sequence, the timing-faithful slow path the compiled scan is measured
-    against.  Returns ``(lengths (B,), tokens (B, width))`` numpy arrays,
-    PAD-filled past each row's length, mirroring
-    :func:`batched_greedy_decode`'s contract.
+    against.  ``src_mask`` is what :func:`row_lengths` takes.  Returns
+    ``(lengths (B,), tokens (B, width))`` numpy arrays, PAD-filled past
+    each row's length, mirroring :func:`batched_greedy_decode`'s contract.
     """
     src = np.asarray(src_tokens, np.int32)
-    b, n = src.shape
-    mask = (np.ones((b, n), np.float32) if src_mask is None
-            else np.asarray(src_mask))
-    src_lens = mask.astype(bool).sum(axis=1)
+    src_lens = row_lengths(src_mask, *src.shape)
+    b = src.shape[0]
     lengths = np.zeros((b,), np.int32)
     rows = []
     for i in range(b):

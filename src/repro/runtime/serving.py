@@ -228,6 +228,17 @@ def _batched_executor(session: "GenerationSession", *,
     return executor
 
 
+def _start_copies_to_host(*arrays) -> bool:
+    """Start each output's copy to the host without waiting for it; True
+    when every one is a device array whose copy was started (a NumPy
+    output has none to start)."""
+    starts = [getattr(a, "copy_to_host_async", None) for a in arrays]
+    for start in starts:
+        if start is not None:
+            start()
+    return all(start is not None for start in starts)
+
+
 def _nmt_batched_executor(model, params, *,
                           vocab_clip: Optional[int] = None) -> Callable:
     """REAL batched executor for an NMT seq2seq model (the paper's tiers).
@@ -238,11 +249,22 @@ def _nmt_batched_executor(model, params, *,
     per bucket; padding rows and columns are masked out and dropped.  The
     translate function is exposed as ``executor.translate``.
 
+    A translate crosses the host-device boundary once each way.  The
+    padded int32 block and its int32 row lengths stay on the host until
+    the one jitted call uploads them, folded into one array; the program
+    builds the prefix mask itself.  Right after the call returns, the
+    copies of both outputs to the host are started, so they run as soon
+    as the program ends and the read-back finds them there.
+
     Traced as an ``exec.translate`` span (attrs ``b``, ``w``: the padded
-    bucket) holding ``exec.dispatch`` (the asynchronous enqueue) and
-    ``exec.wait`` (the device work and the read-back).
+    bucket) holding ``exec.dispatch`` (the asynchronous enqueue; attr
+    ``h2d``, the host arrays the jitted call uploads, as the translate
+    states it, else None) and ``exec.wait`` (the device work and the
+    read-back; attr ``prefetch``, true when the outputs' copies to the
+    host were started at dispatch).
     """
     translate = model.make_translate_batched(params)
+    h2d = getattr(translate, "h2d", None)
 
     def executor(batch: np.ndarray, lengths: Optional[Sequence[int]] = None):
         with tracing.span("exec.translate") as sp:
@@ -254,11 +276,10 @@ def _nmt_batched_executor(model, params, *,
             src[:b, :w] = toks
             lens = np.zeros((bb,), np.int32)
             lens[:b] = lens_in
-            mask = (np.arange(wb)[None, :] < lens[:, None]).astype(
-                np.float32)
-            with tracing.span("exec.dispatch"):
-                m_out, out = translate(src, mask)
-            with tracing.span("exec.wait"):
+            with tracing.span("exec.dispatch", h2d=h2d):
+                m_out, out = translate(src, lens)
+                prefetch = _start_copies_to_host(m_out, out)
+            with tracing.span("exec.wait", prefetch=prefetch):
                 m_out = np.asarray(m_out, np.int32)
                 out = np.asarray(out, np.int32)
             return [(int(m_out[i]), out[i, :max(int(m_out[i]), 1)])

@@ -89,6 +89,62 @@ def test_batched_scan_matches_host_loop_bitwise(name, model, forced_len):
                                   t_host[i, :forced_len])
 
 
+class _Forced:
+    """``model`` whose batched translate runs ``forced_len`` steps (the
+    executor passes none) and records what the executor hands it."""
+
+    def __init__(self, model, forced_len):
+        self.model, self.forced_len, self.calls = model, forced_len, []
+
+    def make_translate_batched(self, params):
+        translate = self.model.make_translate_batched(params)
+
+        def forced(src, src_mask=None):
+            self.calls.append((np.shape(src), np.shape(src_mask)))
+            return translate(src, src_mask, forced_len=self.forced_len)
+        return forced
+
+
+@pytest.mark.parametrize("name,model", _models())
+@pytest.mark.parametrize("forced_len", [None, 9])
+def test_nmt_executor_matches_mask_path_and_host_loop_bitwise(
+        name, model, forced_len):
+    """The executor sends the padded block and its row lengths (the
+    program builds the mask): on ragged blocks in padded (batch, width)
+    buckets its tokens and lengths are bit-identical to the explicit-mask
+    call and to the per-sequence host loop."""
+    params = model.init(jax.random.PRNGKey(4))
+    lens = [5, 9, 3]                       # buckets to (4, 16)
+    src, mask = _ragged_batch(np.random.default_rng(4), lens)
+    wrapped = _Forced(model, forced_len)
+    outs = build_executor(wrapped, kind="batched", params=params)(src, lens)
+    assert wrapped.calls == [((4, 16), (4,))]   # the block, its lengths
+    l_mask, t_mask = model.make_translate_batched(params)(
+        src, mask, forced_len=forced_len)
+    l_mask, t_mask = np.asarray(l_mask), np.asarray(t_mask)
+    l_host, t_host = model.make_translate_batched(params, compiled=False)(
+        src, mask, forced_len=forced_len)
+    assert np.array_equal(l_mask, l_host)
+    assert len(outs) == len(lens)
+    for i, (m, toks) in enumerate(outs):
+        assert m == int(l_mask[i]) == int(l_host[i])
+        if forced_len is not None:
+            assert m == forced_len
+        assert np.array_equal(toks, t_mask[i, :max(m, 1)])
+        assert np.array_equal(toks, t_host[i, :max(m, 1)])
+
+
+def test_batched_translate_refuses_a_mask_with_holes():
+    name, model = _models()[2]
+    params = model.init(jax.random.PRNGKey(5))
+    src, mask = _ragged_batch(np.random.default_rng(5), [4, 6])
+    mask[1, 2] = 0.0
+    for compiled in (True, False):
+        with pytest.raises(ValueError, match="prefix mask"):
+            model.make_translate_batched(params, compiled=compiled)(
+                src, mask)
+
+
 def test_batched_scan_matches_per_sequence_translate():
     """Row i of the batch == translate() of the trimmed row alone."""
     name, model = _models()[2]

@@ -221,6 +221,60 @@ def test_submit_batch_records_the_decision_and_translate_spans(
         assert t.t0_ns <= kids[0].t0_ns <= kids[1].t1_ns <= t.t1_ns
 
 
+def test_translate_crosses_the_boundary_once_each_way(marian_executor):
+    """Each translate uploads one host array (the block with its lengths
+    folded in) in its jitted call and starts both outputs' copies to the
+    host at dispatch; the spans nest as before."""
+    _serve(marian_executor, LENGTHS[:1])
+    tracing.reset()
+    res = _serve(marian_executor, LENGTHS)
+    recs = tracing.spans()
+    by = _by_name(recs)
+    ids = {r.id: r for r in recs}
+    assert len(by["exec.translate"]) == \
+        sum(r.tier_name == "edge" for r in res) > 0
+    for name in ("exec.dispatch", "exec.wait"):
+        assert len(by[name]) == len(by["exec.translate"])
+        assert all(ids[r.parent].name == "exec.translate" for r in by[name])
+    assert all(ids[t.parent].name == "engine.submit_batch"
+               for t in by["exec.translate"])
+    assert all(r.attrs["h2d"] == 1 for r in by["exec.dispatch"])
+    assert all(r.attrs["prefetch"] is True for r in by["exec.wait"])
+
+
+def test_executor_serves_numpy_outputs_without_prefetch():
+    """A translate that hands back NumPy arrays (a wrapper that edits the
+    tokens on the host, as the benchmark's fault test does) is served
+    as is: nothing to copy, so ``prefetch`` is false."""
+    model = MarianTransformer(TransformerConfig(
+        vocab_src=V, vocab_tgt=V, d_model=32, heads=4, d_ff=64,
+        enc_layers=1, dec_layers=1, max_decode_len=8, max_src_len=32))
+    params = model.init(jax.random.PRNGKey(0))
+
+    class OnHost:
+        def make_translate_batched(self, p):
+            translate = model.make_translate_batched(p)
+
+            def host(src, src_mask=None, forced_len=None):
+                lens, toks = translate(src, src_mask, forced_len=forced_len)
+                return np.asarray(lens), np.asarray(toks)
+            return host
+
+    rng = np.random.default_rng(2)
+    block = np.full((2, 9), 0, np.int32)
+    block[0, :9] = rng.integers(3, V, 9)
+    block[1, :4] = rng.integers(3, V, 4)
+    want = build_executor(model, kind="batched", params=params)(block, [9, 4])
+    tracing.reset()
+    got = build_executor(OnHost(), kind="batched", params=params)(
+        block, [9, 4])
+    assert [m for m, _ in got] == [m for m, _ in want]
+    for (_, a), (_, b) in zip(got, want):
+        assert np.array_equal(a, b)
+    (wait,) = tracing.spans("exec.wait")
+    assert wait.attrs["prefetch"] is False
+
+
 def test_decision_and_modelled_tier_create_no_device_array():
     """The regressor and the planes run on the host: a decision, and a
     request served by a modelled tier, neither put an array on a device
